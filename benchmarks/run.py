@@ -69,6 +69,9 @@ def main(argv=None) -> int:
     if unknown:
         ap.error(f"unknown bench(es) {unknown}; choose from {MODULES}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.quick:
         # Set before bench modules import/run so common.is_quick() and
         # any subprocesses they spawn agree.

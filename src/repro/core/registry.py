@@ -28,6 +28,7 @@ bytes as the serial sweep.
 from __future__ import annotations
 
 import dataclasses
+import fcntl
 import functools
 import hashlib
 import json
@@ -87,14 +88,12 @@ def fingerprint(obj: Any) -> str:
 
 
 def runtime_fingerprint() -> str:
-    """Fingerprint of the live JAX runtime (for measured results)."""
-    try:
-        import jax
-        info = {"platform": jax.default_backend(),
-                "device_count": jax.device_count()}
-    except Exception:  # pragma: no cover - jax always present in this repo
-        info = {"platform": "unknown", "device_count": 0}
-    return fingerprint(info)
+    """Fingerprint of the live JAX runtime (for measured results).  A
+    runtime that cannot be queried raises: a measurement filed under a
+    made-up platform would be matched against the wrong machine."""
+    import jax
+    return fingerprint({"platform": jax.default_backend(),
+                        "device_count": jax.device_count()})
 
 
 # ---------------------------------------------------------------------------
@@ -339,22 +338,21 @@ class TuningRegistry:
         # concurrent writers, bytes never do.  If a previous writer died
         # mid-append the file can end without a newline; lead with one so
         # this record starts a fresh line instead of extending the torn
-        # tail (load() then skips exactly one malformed line).
+        # tail (load() then skips exactly one malformed line).  The tail
+        # check and the append hold one exclusive lock, so the check never
+        # sees another writer's line half-written (which would add a
+        # blank line).
         buf = line.encode("utf-8")
+        fd = os.open(self.path, os.O_RDWR | os.O_CREAT | os.O_APPEND, 0o644)
         try:
-            with open(self.path, "rb") as f:
-                f.seek(-1, os.SEEK_END)
-                if f.read(1) != b"\n":
-                    buf = b"\n" + buf
-        except (OSError, ValueError):
-            pass  # missing or empty file: nothing to repair
-        fd = os.open(self.path, os.O_WRONLY | os.O_CREAT | os.O_APPEND,
-                     0o644)
-        try:
+            fcntl.flock(fd, fcntl.LOCK_EX)
+            size = os.fstat(fd).st_size
+            if size and os.pread(fd, 1, size - 1) != b"\n":
+                buf = b"\n" + buf
             os.write(fd, buf)
             os.fsync(fd)  # durable before we report the record stored
         finally:
-            os.close(fd)
+            os.close(fd)  # releases the lock
 
     def compact(self) -> None:
         """Rewrite the log as one canonical line per key, sorted by key.

@@ -37,7 +37,7 @@ class ConvSchedule:
         return registry.schedule_to_dict(self)
 
     def run(self, img: jnp.ndarray, wgt: jnp.ndarray, *,
-            interpret: bool = True) -> jnp.ndarray:
+            interpret: Optional[bool] = None) -> jnp.ndarray:
         """Execute the conv2d kernel with this schedule's parameters."""
         from repro.kernels.conv2d import conv2d
         return conv2d(img, wgt, block=self.block_dict(),
@@ -69,7 +69,7 @@ class MatmulSchedule:
         return registry.schedule_to_dict(self)
 
     def run(self, a: jnp.ndarray, b: jnp.ndarray, *,
-            interpret: bool = True) -> jnp.ndarray:
+            interpret: Optional[bool] = None) -> jnp.ndarray:
         """Execute the matmul kernel with this schedule's parameters."""
         from repro.kernels.matmul import matmul
         return matmul(a, b, block=self.block_dict(),
@@ -90,7 +90,7 @@ class FlashAttentionSchedule:
 
     def run(self, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             causal: bool = True, window: Optional[int] = None,
-            interpret: bool = True) -> jnp.ndarray:
+            interpret: Optional[bool] = None) -> jnp.ndarray:
         """Execute flash attention with this schedule's block sizes."""
         from repro.kernels.flash_attention import flash_attention
         return flash_attention(q, k, v, block_q=self.block_q,
@@ -109,7 +109,7 @@ class DecodeAttentionSchedule:
         return registry.schedule_to_dict(self)
 
     def run(self, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-            pos, *, interpret: bool = True) -> jnp.ndarray:
+            pos, *, interpret: Optional[bool] = None) -> jnp.ndarray:
         """Execute one decode attention step with this schedule."""
         from repro.kernels.decode_attention import decode_attention
         return decode_attention(q, k, v, pos, block_kv=self.block_kv,
@@ -127,7 +127,7 @@ class SSMScanSchedule:
         return registry.schedule_to_dict(self)
 
     def run(self, x, dt, b, c, a, d, *,
-            interpret: bool = True) -> jnp.ndarray:
+            interpret: Optional[bool] = None) -> jnp.ndarray:
         """Execute the fused selective scan with this schedule."""
         from repro.kernels.ssm_scan import ssm_scan
         return ssm_scan(x, dt, b, c, a, d, block_d=self.block_d,
@@ -191,7 +191,7 @@ class SparseConvSchedule:
         return registry.schedule_to_dict(self)
 
     def run(self, img: jnp.ndarray, wgt: jnp.ndarray, *,
-            sparsity=None, interpret: bool = True) -> jnp.ndarray:
+            sparsity=None, interpret: Optional[bool] = None) -> jnp.ndarray:
         """Execute the block-sparse conv kernel with this schedule."""
         from repro.kernels.sparse_conv import sparse_conv2d
         return sparse_conv2d(img, wgt, block=self.block_dict(),

@@ -386,8 +386,10 @@ def tune_ssm_scan(bt: int, seq: int, di: int, n: int,
                   spec: cm.TPUSpec = cm.TPUSpec(),
                   elem_bytes: int = 2, top_k: int = 5,
                   ) -> List[Tuple[SSMScanSchedule, cm.KernelCost]]:
-    """Rank channel blocks for the fused selective scan."""
-    d_c = _block_candidates(di, (32, 64, 128, 256, di))
+    """Rank channel blocks for the fused selective scan.  The channel
+    block is the kernel's lane dimension, so on TPU it is a multiple of
+    128 or all of ``di``."""
+    d_c = _block_candidates(di, (128, 256, 512, di))
     batch = cm.ssm_scan_schedule_cost_batch(bt, seq, di, n, d_c, spec,
                                             elem_bytes)
     top = np.argsort(batch.time_s, kind="stable")[:top_k]
@@ -617,6 +619,13 @@ def cached_sweep_layer(layer: ConvLayer,
 # the trace-driven validator (core/tracesim) really does cost seconds per
 # permutation and still wants the fan-out.
 
+def _cpu_only_worker() -> None:
+    """Pool initializer: a worker that touches JAX gets the CPU backend,
+    never the parent's accelerator (tracesim itself is pure numpy)."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _map_parallel(fn, jobs: Sequence, workers: Optional[int]) -> List:
     """Map ``fn`` over ``jobs`` preserving order.  ``workers`` None/0/1 =>
     serial; otherwise a process pool (tracesim is pure Python, so threads
@@ -625,7 +634,9 @@ def _map_parallel(fn, jobs: Sequence, workers: Optional[int]) -> List:
 
     Uses a forkserver/spawn start method, never plain fork: the parent
     has usually initialised JAX by the time a validation runs, and forking
-    a multithreaded JAX process can deadlock."""
+    a multithreaded JAX process can deadlock.  Workers are pinned to the
+    CPU backend before they run anything: a chip belongs to one process,
+    and the parent may hold it."""
     if not workers or workers <= 1 or len(jobs) <= 1:
         return [fn(j) for j in jobs]
     import multiprocessing as mp
@@ -634,7 +645,8 @@ def _map_parallel(fn, jobs: Sequence, workers: Optional[int]) -> List:
     try:
         ctx = mp.get_context(method)
         with concurrent.futures.ProcessPoolExecutor(
-                workers, mp_context=ctx) as ex:
+                workers, mp_context=ctx,
+                initializer=_cpu_only_worker) as ex:
             return list(ex.map(fn, jobs))
     except (OSError, PermissionError, concurrent.futures.process
             .BrokenProcessPool):

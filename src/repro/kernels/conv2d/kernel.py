@@ -22,12 +22,14 @@ The MXU mapping: each (ky, kx) tap is a [BOC, BIC] x [BIC, BY*BX] matmul
 from __future__ import annotations
 
 import functools
-from typing import Dict, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 GRID_AXES: Tuple[str, ...] = ("oc", "ic", "y", "x")
 
@@ -96,7 +98,7 @@ def _conv_kernel_rmw(img_ref, wgt_ref, out_ref, *,
 def conv2d_pallas(img: jnp.ndarray, wgt: jnp.ndarray, *,
                   block: Dict[str, int],
                   grid_order: Sequence[str] = ("oc", "y", "x", "ic"),
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """Direct conv via pallas_call.
 
     img: [N, IC, H+KH-1, W+KW-1]; wgt: [OC, IC, KH, KW].
@@ -155,10 +157,10 @@ def conv2d_pallas(img: jnp.ndarray, wgt: jnp.ndarray, *,
             grid=grid, in_specs=in_specs, out_specs=out_spec,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((boc, by, bx), jnp.float32)],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(img, wgt)
     return pl.pallas_call(
         functools.partial(_conv_kernel_rmw, **common),
         grid=grid, in_specs=in_specs, out_specs=out_spec,
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, interpret=resolve_interpret(interpret),
     )(img, wgt)

@@ -46,7 +46,7 @@ def _conv2d_jit(img, wgt, block_tuple, grid_order, interpret):
 def conv2d(img: jnp.ndarray, wgt: jnp.ndarray, *,
            block: Optional[Dict[str, int]] = None,
            grid_order: Sequence[str] = ("oc", "y", "x", "ic"),
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Direct convolution, thesis semantics (valid, pre-padded input).
 
     img: [N, IC, H+KH-1, W+KW-1]; wgt: [OC, IC, KH, KW] -> [N, OC, H, W].
@@ -75,7 +75,7 @@ def _tuned_schedule(shape_key: Tuple[int, ...], elem_bytes: int,
 
 
 def conv2d_tuned(img: jnp.ndarray, wgt: jnp.ndarray, *,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """`conv2d` with the schedule picked by the tuning registry.
 
     First call on a new problem shape pays one cost-model sweep and
@@ -93,7 +93,7 @@ def conv2d_tuned(img: jnp.ndarray, wgt: jnp.ndarray, *,
 
 
 def conv2d_scheduled(img: jnp.ndarray, wgt: jnp.ndarray, *, schedule,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Schedule-as-static-arg entry point: run ``conv2d`` with a
     committed :class:`~repro.core.schedule.ConvSchedule` (frozen,
     hashable — the underlying jit keys on its block/grid order)."""
@@ -102,7 +102,7 @@ def conv2d_scheduled(img: jnp.ndarray, wgt: jnp.ndarray, *, schedule,
 
 
 def conv2d_dispatched(img: jnp.ndarray, wgt: jnp.ndarray, *,
-                      service=None, interpret: bool = True) -> jnp.ndarray:
+                      service=None, interpret: Optional[bool] = None) -> jnp.ndarray:
     """`conv2d` through the adaptive dispatch runtime: the process-wide
     :class:`~repro.runtime.dispatch.DispatchService` proposes one of the
     registry-backed top-K schedules, the call is timed, and the

@@ -14,11 +14,14 @@ GQA is handled by the KV index map folding query heads onto their group
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -70,7 +73,7 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray,
                             v: jnp.ndarray, pos: jnp.ndarray, *,
                             starts: jnp.ndarray = None,
                             block_kv: int = 256,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: Optional[bool] = None) -> jnp.ndarray:
     """q [B,HQ,1,D]; k/v [B,HKV,S,D]; pos scalar or [B] int32.
 
     ``starts`` ([B] int32, optional) marks each row's first valid cache
@@ -122,7 +125,7 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray,
         functools.partial(_decode_kernel, bkv=bkv, n_kv=n_kv, hq=hq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos_arr, starts_arr, qf, kf, vf)
     return out.reshape(b, hq, 1, d)
 
@@ -179,7 +182,7 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
                                   v_pool: jnp.ndarray,
                                   tables: jnp.ndarray,
                                   pos: jnp.ndarray, *,
-                                  interpret: bool = True) -> jnp.ndarray:
+                                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """q [B,HQ,1,D]; pools [NB,HKV,bs,D]; tables [B,MB] int32; pos [B].
 
     The thesis' scalar-prefetch sparsity guard applied to paging: the
@@ -224,6 +227,6 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
         functools.partial(_paged_decode_kernel, bs=bs, mb=mb, hq=hq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tables_flat, pos_arr, qf, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,7 @@ from repro.kernels.decode_attention.ref import decode_attention_ref
 
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret"))
 def decode_attention(q, k, v, pos, *, starts=None, block_kv: int = 256,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """Single-query flash decode; ``pos`` scalar or [B], ``starts``
     optional [B] first-valid cache index per row (left padding)."""
     return decode_attention_pallas(q, k, v, pos, starts=starts,
@@ -23,7 +24,7 @@ def decode_attention(q, k, v, pos, *, starts=None, block_kv: int = 256,
 
 @functools.partial(jax.jit, static_argnames=("schedule", "interpret"))
 def decode_attention_scheduled(q, k, v, pos, *, schedule, starts=None,
-                               interpret: bool = True):
+                               interpret: Optional[bool] = None):
     """Schedule-as-static-arg entry point: the compiled decode step
     threads a committed :class:`~repro.core.schedule.
     DecodeAttentionSchedule` (frozen, hashable) straight into the
@@ -35,7 +36,7 @@ def decode_attention_scheduled(q, k, v, pos, *, schedule, starts=None,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """Block-table-aware flash decode over a paged KV pool.
 
     q [B,HQ,1,D]; pools [NB,HKV,bs,D]; tables [B,MB] int32; pos [B]
@@ -46,7 +47,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
 
 
 def decode_attention_dispatched(q, k, v, pos, *, service=None,
-                                interpret: bool = True):
+                                interpret: Optional[bool] = None):
     """`decode_attention` through the adaptive dispatch runtime: the KV
     streaming block for this (B, HQ, HKV, S, D) cache shape comes from
     the registry-backed top-K and each call's measured time feeds the

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -157,7 +159,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            causal: bool = True,
                            window: Optional[int] = None,
                            starts: Optional[jnp.ndarray] = None,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: [B, HQ, S, D]; k, v: [B, HKV, S, D] -> [B, HQ, S, D].
 
     ``starts`` ([B] int32, optional): per-row first real token index for
@@ -200,7 +202,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 pltpu.VMEM((bq, 1), jnp.float32),
                 pltpu.VMEM((bq, d), jnp.float32),
             ],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(qf, kf, vf)
         return out.reshape(b, hq, s, d)
 
@@ -235,6 +237,6 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           hq=hq),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, s, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(starts_arr, qf, kf, vf)
     return out.reshape(b, hq, s, d)

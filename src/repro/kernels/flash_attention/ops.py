@@ -16,7 +16,7 @@ from repro.kernels.flash_attention.ref import mha_ref
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     block_q: int = 128, block_kv: int = 128,
                     causal: bool = True, window: Optional[int] = None,
-                    starts=None, interpret: bool = True) -> jnp.ndarray:
+                    starts=None, interpret: Optional[bool] = None) -> jnp.ndarray:
     """Flash attention; q [B,HQ,S,D], k/v [B,HKV,S,D] -> [B,HQ,S,D].
 
     ``starts`` ([B] int32, optional) masks keys below each row's first
@@ -34,7 +34,7 @@ def flash_attention_scheduled(q: jnp.ndarray, k: jnp.ndarray,
                               causal: bool = True,
                               window: Optional[int] = None,
                               starts=None,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: Optional[bool] = None) -> jnp.ndarray:
     """Schedule-as-static-arg entry point: a committed
     :class:`~repro.core.schedule.FlashAttentionSchedule` (frozen,
     hashable) keys the compiled executable.  Blocks are clamped to the
@@ -51,7 +51,7 @@ def flash_attention_dispatched(q: jnp.ndarray, k: jnp.ndarray,
                                v: jnp.ndarray, *, causal: bool = True,
                                window: Optional[int] = None,
                                service=None,
-                               interpret: bool = True) -> jnp.ndarray:
+                               interpret: Optional[bool] = None) -> jnp.ndarray:
     """`flash_attention` through the adaptive dispatch runtime: the
     (block_q, block_kv) schedule comes from the registry-backed top-K
     for this (B, HQ, HKV, S, D) shape and the measured call time feeds

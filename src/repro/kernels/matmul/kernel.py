@@ -16,12 +16,14 @@ in interpret mode; charged by the cost model on hardware).
 from __future__ import annotations
 
 import functools
-from typing import Dict, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 GRID_AXES: Tuple[str, ...] = ("m", "n", "k")
 
@@ -79,7 +81,7 @@ def matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
                   block: Dict[str, int],
                   grid_order: Sequence[str] = ("m", "n", "k"),
                   resident_rhs: bool = False,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """C[m,n] = A[m,k] @ B[k,n] with explicit BlockSpec VMEM tiling."""
     m, k = a.shape
     k2, n = b.shape
@@ -99,7 +101,7 @@ def matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(a, b)
 
     assert sorted(grid_order) == sorted(GRID_AXES), grid_order
@@ -123,10 +125,10 @@ def matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
             grid=grid, in_specs=[a_spec, b_spec], out_specs=o_spec,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(a, b)
     return pl.pallas_call(
         functools.partial(_mm_rmw_kernel, k_pos=pos["k"], n_k=trips["k"]),
         grid=grid, in_specs=[a_spec, b_spec], out_specs=o_spec,
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, interpret=resolve_interpret(interpret),
     )(a, b)

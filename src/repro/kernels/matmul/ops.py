@@ -40,7 +40,7 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *,
            block: Optional[Dict[str, int]] = None,
            grid_order: Sequence[str] = ("m", "n", "k"),
            resident_rhs: bool = False,
-           interpret: bool = True) -> jnp.ndarray:
+           interpret: Optional[bool] = None) -> jnp.ndarray:
     m, k = a.shape
     _, n = b.shape
     if block is None:
@@ -61,7 +61,7 @@ def _tuned_schedule(mnk: Tuple[int, int, int], elem_bytes: int,
 
 
 def matmul_tuned(a: jnp.ndarray, b: jnp.ndarray, *,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """`matmul` with the schedule picked by the tuning registry; tunes at
     most once per (m, n, k, dtype) per machine — ever."""
     from repro.core.registry import TuningRegistry
@@ -75,7 +75,7 @@ def matmul_tuned(a: jnp.ndarray, b: jnp.ndarray, *,
 
 
 def matmul_scheduled(a: jnp.ndarray, b: jnp.ndarray, *, schedule,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Schedule-as-static-arg entry point: run ``matmul`` with a
     committed :class:`~repro.core.schedule.MatmulSchedule` (frozen,
     hashable — the underlying jit keys on its block/grid/residency)."""
@@ -85,7 +85,7 @@ def matmul_scheduled(a: jnp.ndarray, b: jnp.ndarray, *, schedule,
 
 
 def matmul_dispatched(a: jnp.ndarray, b: jnp.ndarray, *,
-                      service=None, interpret: bool = True) -> jnp.ndarray:
+                      service=None, interpret: Optional[bool] = None) -> jnp.ndarray:
     """`matmul` through the adaptive dispatch runtime (see
     :mod:`repro.runtime.dispatch`): propose a registry-backed candidate,
     time the call, feed the selector, commit + write back once steady."""

@@ -22,13 +22,15 @@ thesis makes.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 
 def build_block_index(mask: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -77,7 +79,7 @@ def _sparse_kernel(idx_ref, cnt_ref, img_ref, wgt_ref, out_ref, acc_ref, *,
 def sparse_conv2d_pallas(img: jnp.ndarray, wgt: jnp.ndarray,
                          idx: jnp.ndarray, counts: jnp.ndarray, *,
                          block: Dict[str, int],
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """img: [N, IC, H+KH-1, W+KW-1]; wgt: [OC, IC, KH, KW];
     idx/counts from :func:`build_block_index` over (oc, ic) blocks.
     Output blocks keep full spatial extent (thesis-scale images are small);
@@ -114,5 +116,5 @@ def sparse_conv2d_pallas(img: jnp.ndarray, wgt: jnp.ndarray,
         functools.partial(_sparse_kernel, kh=kh, kw=kw, n_steps=n_steps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, oc, h, w), img.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(idx, counts, img, wgt)

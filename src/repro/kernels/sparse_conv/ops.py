@@ -49,7 +49,7 @@ def analyze_weights(wgt: np.ndarray, block: Dict[str, int],
 def sparse_conv2d(img: jnp.ndarray, wgt: jnp.ndarray, *,
                   block: Dict[str, int],
                   sparsity: Optional[BlockSparsity] = None,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """Block-sparse direct conv; recomputes structure if not supplied."""
     if sparsity is None:
         sparsity = analyze_weights(np.asarray(wgt), block)
@@ -61,7 +61,7 @@ def sparse_conv2d(img: jnp.ndarray, wgt: jnp.ndarray, *,
 def sparse_conv2d_scheduled(img: jnp.ndarray, wgt: jnp.ndarray, *,
                             schedule,
                             sparsity: Optional[BlockSparsity] = None,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: Optional[bool] = None) -> jnp.ndarray:
     """Schedule-as-static-arg entry point: run ``sparse_conv2d`` with a
     committed :class:`~repro.core.schedule.SparseConvSchedule` skip-block
     shape (frozen, hashable)."""
@@ -72,7 +72,7 @@ def sparse_conv2d_scheduled(img: jnp.ndarray, wgt: jnp.ndarray, *,
 def sparse_conv2d_dispatched(img: jnp.ndarray, wgt: jnp.ndarray, *,
                              density: Optional[float] = None,
                              service=None,
-                             interpret: bool = True) -> jnp.ndarray:
+                             interpret: Optional[bool] = None) -> jnp.ndarray:
     """`sparse_conv2d` through the adaptive dispatch runtime: the (oc,
     ic) skip-block shape for this (layer, density) comes from the
     registry-backed top-K and each call's measured time feeds the online

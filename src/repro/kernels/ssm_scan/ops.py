@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import functools
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +13,7 @@ from repro.kernels.ssm_scan.ref import ssm_scan_ref
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def ssm_scan(x, dt, b, c, a, d, *, block_d: int = 128,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     y, _ = ssm_scan_pallas(x, dt, b, c, a, d, block_d=block_d,
                            interpret=interpret)
     return y
@@ -21,7 +21,7 @@ def ssm_scan(x, dt, b, c, a, d, *, block_d: int = 128,
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def ssm_scan_with_state(x, dt, b, c, a, d, h0=None, *,
-                        block_d: int = 128, interpret: bool = True):
+                        block_d: int = 128, interpret: Optional[bool] = None):
     """Fused scan carrying an explicit state: ``h0`` [Bt, Di, N] (zeros
     when None) in, final state out — the decode-cache form the model
     step uses.  Returns (y [Bt, S, Di], h_final [Bt, Di, N] f32)."""
@@ -31,7 +31,7 @@ def ssm_scan_with_state(x, dt, b, c, a, d, h0=None, *,
 
 @functools.partial(jax.jit, static_argnames=("schedule", "interpret"))
 def ssm_scan_scheduled(x, dt, b, c, a, d, h0=None, *, schedule,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Schedule-as-static-arg entry point: the compiled model step
     threads a committed :class:`~repro.core.schedule.SSMScanSchedule`
     (frozen, hashable) straight into the launch — a different committed
@@ -55,7 +55,7 @@ def traffic_model(bt: int, seq: int, di: int, n: int,
 
 
 def ssm_scan_dispatched(x, dt, b, c, a, d, *, service=None,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """`ssm_scan` through the adaptive dispatch runtime: the channel
     block for this (Bt, S, Di, N) shape comes from the registry-backed
     top-K and each call's measured time feeds the online selector (see

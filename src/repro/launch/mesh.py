@@ -10,22 +10,14 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """`axis_types=` for jax.make_mesh where supported (jax >= 0.5); older
-    jaxlibs default to Auto anyway, so omitting it is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh helper for tests / small dry-runs."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -41,6 +41,20 @@ def _load_requests(path, n_default, prompt_len, new_tokens, vocab, rng):
             for i in range(n_default)]
 
 
+def _exit_on_silent_failure(results, stats) -> None:
+    """Exit nonzero when the fault-tolerance layer absorbed a failure
+    that nobody injected: a FAILED request, a bucket degraded to the
+    reference backend, or an AOT compile that fell back to the jit fn.
+    Those keep a production stream alive, but a run that reports
+    success must have run what it was asked to run."""
+    failed = sum(r.state == "FAILED" for r in results)
+    if failed or stats.degraded or stats.fallbacks:
+        raise SystemExit(
+            f"serve: {failed} failed request(s), "
+            f"{stats.degraded_buckets} degraded bucket(s), "
+            f"{stats.fallbacks} AOT fallback(s) with no --inject-fault")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -134,6 +148,9 @@ def main() -> None:
                          "postmortem-<reason>.json bundle into DIR "
                          "(--session)")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -278,14 +295,17 @@ def main() -> None:
                 for reason, n in sorted(recorder.dumps.items()))
                 + f" (in {recorder.out_dir}/)")
         _write_telemetry()
+        if not args.inject_fault:
+            _exit_on_silent_failure(results, session.stats)
         return
 
+    from repro.serving import ServeSession
+    session = ServeSession(model, params, dispatch=dispatch,
+                           backend=args.backend, registry=registry,
+                           max_recompiles=args.max_recompiles)
     out, stats = generate(model, params, batch,
                           max_new_tokens=args.new_tokens,
-                          temperature=args.temperature,
-                          registry=registry, dispatch=dispatch,
-                          backend=args.backend,
-                          max_recompiles=args.max_recompiles)
+                          temperature=args.temperature, session=session)
     print(f"generated {out.shape}; prefill {stats.prefill_s*1e3:.1f}ms; "
           f"decode {stats.decode_tok_s:.0f} tok/s; "
           f"backend={stats.backend} recompiles={stats.recompiles}")
@@ -298,6 +318,7 @@ def main() -> None:
             print(f"dispatch {entry['kind']}: obs={entry['observations']}"
                   f" committed={committed if committed else '(probing)'}")
     _write_telemetry()
+    _exit_on_silent_failure([], session.stats)
 
 
 if __name__ == "__main__":

@@ -34,6 +34,9 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import logging
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
 

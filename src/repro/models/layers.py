@@ -9,6 +9,7 @@ fallbacks, MaxText-style, so models never hard-code mesh details.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -17,6 +18,15 @@ import jax.numpy as jnp
 
 Params = Dict[str, Any]
 Axes = Tuple[Optional[str], ...]
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _scaled_normal(rng: jax.Array, shape: Tuple[int, ...], std: float,
+                   dtype) -> jnp.ndarray:
+    """f32 normal * std cast to ``dtype``, fused into one program so that
+    only the ``dtype`` result is allocated: a published-width weight
+    (e.g. [32, 3072, 8192]) never has f32 temporaries beside it."""
+    return (jax.random.normal(rng, shape, jnp.float32) * std).astype(dtype)
 
 
 class ParamBuilder:
@@ -44,8 +54,7 @@ class ParamBuilder:
         assert len(shape) == len(axes), (path, shape, axes)
         std = scale / math.sqrt(fan_in if fan_in else shape[-2]
                                 if len(shape) >= 2 else shape[-1])
-        v = (jax.random.normal(self._next_rng(), tuple(shape), jnp.float32)
-             * std).astype(self.dtype)
+        v = _scaled_normal(self._next_rng(), tuple(shape), std, self.dtype)
         self._put(self.params, path, v)
         self._put(self.axes, path, tuple(axes))
 

@@ -200,7 +200,8 @@ def left_pad_prompts(prompts, target_len: int, pad_id: int = 0):
     the matching :func:`prompt_starts` vector as ``seq_starts`` to
     ``prefill``/``decode_step`` so pad tokens are masked out of
     attention (and out of the SSM recurrence): a padded row then
-    produces logits bit-identical to its unpadded equivalent.
+    produces the same logits as its unpadded equivalent, up to float
+    reduction order.
     """
     import numpy as np
     out = np.full((len(prompts), target_len), int(pad_id), dtype=np.int32)

@@ -218,7 +218,8 @@ def forward(params: Params, cfg: ModelConfig,
     each left-padded row: rope positions become per-row
     ``arange(S) - starts`` and pad positions are masked out of attention
     (or out of the SSM recurrence), so a left-padded row's logits at its
-    real positions are bit-identical to the unpadded row's.  Supported
+    real positions equal the unpadded row's up to float reduction
+    order.  Supported
     for the dense/moe/ssm families (vlm interleaves image tokens and
     hybrid's rolling-window caches assume dense prefixes — both raise).
     """

@@ -109,6 +109,13 @@ class ExecutableCache:
             self._entries.move_to_end(key)
             return self._entries[key], False
 
+    def items(self) -> List[Tuple[ExecKey, Any]]:
+        """Snapshot of the cached ``(key, executable)`` pairs, without
+        LRU or counter side effects (what a check of the compiled code
+        walks)."""
+        with self._lock:
+            return list(self._entries.items())
+
     def compiled_roles(self) -> Dict[str, int]:
         """Compile counts per role (``{"prefill": n, "decode": m}``)."""
         out: Dict[str, int] = {}

@@ -667,8 +667,8 @@ class ServeSession:
         returning the same-signature reference-backend jit fn; after a
         persistent AOT failure with ``fallback_backend="reference"`` the
         bucket's executable is rebuilt from it (``degraded`` flagged) —
-        tokens stay bit-identical (reference == pallas), only the kernel
-        path changes.  Otherwise the un-lowered fn is returned
+        the same model on the XLA kernel path, whose logits agree with
+        the pallas ones to rounding.  Otherwise the un-lowered fn is returned
         (``stats.fallbacks``).
         """
         fn, ok = self._aot_compile(jit_fn, lower_args, what=what)
@@ -862,8 +862,8 @@ class ServeSession:
         overtaking), then (4) runs one decode step over all rows.
         Admission runs a batch-1 masked prefill through the shared
         executable cache and scatters the prompt KV (or SSM state) into
-        the engine, so tokens are bit-identical to running the request
-        alone (greedy).
+        the engine, so its greedy tokens equal running the request alone
+        (logits agree to float reduction order).
         """
         from repro.runtime.serve_loop import (ServeStats,
                                               resolve_bundle_report,

@@ -7,9 +7,6 @@ equal — the arithmetic is sequenced identically), across random layers,
 all 720 permutations, and all three §5.1 cache hierarchies.  This is what
 lets COST_MODEL_VERSION stay at "1" so warm registries survive the
 engine swap.
-
-Property tests run under real hypothesis when installed, else the
-deterministic `_compat` fallback (see tests/conftest.py).
 """
 import itertools
 

@@ -65,7 +65,6 @@ def test_grad_compress_allreduce_shard_map():
     print(run_py("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.optim import grad_compress
 
 from repro.launch.mesh import make_mesh
@@ -77,8 +76,8 @@ def f(gs, es):
     mean, new_err = grad_compress.allreduce_compressed(gs, es, "data")
     return mean, new_err
 
-fm = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-               out_specs=(P("data"), P("data")))
+fm = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                   out_specs=(P("data"), P("data")))
 mean, new_err = fm(g, err)
 expected = g.mean(axis=0)
 got = np.asarray(mean)[0]

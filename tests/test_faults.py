@@ -366,3 +366,29 @@ def test_launch_serve_fault_flags(tmp_path, capsys, monkeypatch):
     assert "session: 2 requests" in out
     assert "FAILED" in out  # the poisoned request's terminal state
     assert "faults:" in out  # fault summary line
+
+
+def test_launch_serve_exits_nonzero_on_unrequested_degradation(
+        tmp_path, monkeypatch):
+    """Every AOT compile fails and nobody injected a fault: the session
+    still completes the request (degraded, on the jit fallback), but the
+    launcher must not report that run as a success."""
+    import jax
+
+    from repro.launch import serve as serve_cli
+
+    def refuse(self, *a, **k):
+        raise RuntimeError("forced AOT failure")
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", refuse)
+    reqs = tmp_path / "requests.jsonl"
+    reqs.write_text('{"prompt_len": 4, "new_tokens": 2}\n')
+    monkeypatch.setattr(
+        "sys.argv",
+        ["serve", "--arch", "phi3-mini-3.8b-smoke", "--session",
+         "--backend", "pallas", "--requests-file", str(reqs),
+         "--batch-sizes", "1"])
+    with pytest.raises(SystemExit) as exc:
+        serve_cli.main()
+    assert exc.value.code not in (0, None)
+    assert "degraded bucket" in str(exc.value.code)

