@@ -24,6 +24,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
+# Name of the paged kernel's custom call in compiled programs and device
+# traces (what a trace reduction matches on).
+PAGED_DECODE_KERNEL_NAME = "paged_decode_attention"
 
 
 def _decode_kernel(pos_ref, starts_ref, q_ref, k_ref, v_ref, o_ref,
@@ -228,5 +231,6 @@ def paged_decode_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hq, 1, d), q.dtype),
         interpret=resolve_interpret(interpret),
+        name=PAGED_DECODE_KERNEL_NAME,
     )(tables_flat, pos_arr, qf, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)

@@ -50,6 +50,9 @@ from repro.kernels.platform import resolve_interpret
 
 CHUNK = 16                 # timesteps per aligned load/store
 STREAM_BYTES = 2 << 20     # x/dt/y bytes per sequence block (x2 buffers)
+# Name of the scan's custom call in compiled programs and device traces
+# (what a trace reduction matches on), for every wrapper that calls it.
+SSM_SCAN_KERNEL_NAME = "ssm_scan_scheduled"
 
 
 def _columns(eye: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
@@ -160,6 +163,7 @@ def ssm_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, b: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=SSM_SCAN_KERNEL_NAME,
     )(*args)
     if interpret:
         y, h_out = jax.lax.optimization_barrier((y, h_out))

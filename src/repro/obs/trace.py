@@ -14,6 +14,16 @@ own horizontal track spanning submit → terminal state, while the
 nested engine spans (step → admit/prefill/decode/compact) live on the
 main thread track.
 
+Every complete span (``span``, or ``begin``/``end`` for a region that
+is not one ``with`` block) is also forwarded to JAX's profiler as a
+``jax.profiler.TraceAnnotation`` of the same name, entered when the
+span starts and exited when it ends.  That adds a few microseconds of
+host time per span, whether or not a profile is being taken; while one
+is (``jax.profiler.trace``, TensorBoard), the span appears on the
+profile's host plane, on the same clock as the device's operations.
+The module never imports JAX: the forwarding happens only in a process
+that has loaded it (no other could be profiled by it).
+
 ``NullTracer`` is the disabled twin: ``enabled`` is ``False`` and
 instrumented code guards on that flag, so a telemetry-off run never
 enters any tracer method (the null fast path, also asserted in
@@ -24,13 +34,39 @@ from __future__ import annotations
 
 import contextlib
 import json
+import sys
 import time
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, NamedTuple, Optional
 
-__all__ = ["SpanTracer", "NullTracer", "TRACE_PID"]
+__all__ = ["SpanTracer", "NullTracer", "OpenSpan", "TRACE_PID", "QUEUE_TID"]
 
 # Single-process stack: one synthetic pid, tid 0 for engine spans.
 TRACE_PID = 1
+# Track of spans that cross engine steps (the queue's holds), so that
+# the engine track stays properly nested.
+QUEUE_TID = 1
+
+
+class OpenSpan(NamedTuple):
+    """A span ``SpanTracer.begin`` opened and ``end`` will record."""
+
+    name: str
+    cat: str
+    tid: int
+    start: float                 # microseconds since tracer start
+    args: Dict[str, Any]
+    annotation: Any              # the entered profiler annotation, or None
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``; None
+    when this process has not loaded JAX."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    annotation = jax.profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
 
 
 class SpanTracer:
@@ -61,6 +97,10 @@ class SpanTracer:
             "ph": "M", "name": "thread_name", "pid": TRACE_PID, "tid": 0,
             "args": {"name": "engine"},
         })
+        self.events.append({
+            "ph": "M", "name": "thread_name", "pid": TRACE_PID,
+            "tid": QUEUE_TID, "args": {"name": "queue"},
+        })
 
     def _ts(self) -> float:
         """Current timestamp in microseconds since tracer start."""
@@ -70,27 +110,30 @@ class SpanTracer:
     def span(self, name: str, cat: str = "repro", tid: int = 0,
              **args: Any) -> Iterator[None]:
         """Record a complete ("X") event covering the ``with`` body."""
-        start = self._ts()
+        opened = self.begin(name, cat, tid, **args)
         try:
             yield
         finally:
-            end = self._ts()
-            self.events.append({
-                "name": name, "cat": cat, "ph": "X",
-                "ts": start, "dur": round(end - start, 3),
-                "pid": TRACE_PID, "tid": tid, "args": args,
-            })
+            self.end(opened)
 
-    def complete(self, name: str, start_s: float, end_s: float,
-                 cat: str = "repro", tid: int = 0, **args: Any) -> None:
-        """Record a complete ("X") event from two explicit readings of
-        this tracer's clock, in seconds (for hot paths where a ``with``
-        block is awkward — e.g. regions with early ``continue``)."""
-        ts = round((start_s - self._t0) * 1e6, 3)
+    def begin(self, name: str, cat: str = "repro", tid: int = 0,
+              **args: Any) -> OpenSpan:
+        """Open a complete ("X") event that ``end`` records: for spans
+        that do not fit one ``with`` block (a queue hold that lasts
+        several engine steps, a region left by ``continue``)."""
+        return OpenSpan(name, cat, tid, self._ts(), args, _annotation(name))
+
+    def end(self, opened: OpenSpan, **args: Any) -> None:
+        """Record the span ``begin`` opened, ending now; ``args`` are
+        added to those given at ``begin``."""
+        end = self._ts()
+        if opened.annotation is not None:
+            opened.annotation.__exit__(None, None, None)
         self.events.append({
-            "name": name, "cat": cat, "ph": "X",
-            "ts": ts, "dur": round((end_s - start_s) * 1e6, 3),
-            "pid": TRACE_PID, "tid": tid, "args": args,
+            "name": opened.name, "cat": opened.cat, "ph": "X",
+            "ts": opened.start, "dur": round(end - opened.start, 3),
+            "pid": TRACE_PID, "tid": opened.tid,
+            "args": {**opened.args, **args},
         })
 
     def instant(self, name: str, cat: str = "repro", tid: int = 0,
@@ -151,8 +194,11 @@ class NullTracer:
         """No-op context manager (never reached when guarded)."""
         return contextlib.nullcontext()
 
-    def complete(self, name: str, start_s: float, end_s: float,
-                 cat: str = "repro", tid: int = 0, **args: Any) -> None:
+    def begin(self, name: str, cat: str = "repro", tid: int = 0,
+              **args: Any) -> None:
+        """No-op."""
+
+    def end(self, opened: Any, **args: Any) -> None:
         """No-op."""
 
     def instant(self, name: str, cat: str = "repro", tid: int = 0,

@@ -69,6 +69,7 @@ from repro.models.model_zoo import (Model, bucket_length,
 from repro.obs.events import Event
 from repro.obs.recorder import POSTMORTEM_KINDS
 from repro.obs.telemetry import NULL_TELEMETRY
+from repro.obs.trace import QUEUE_TID
 from repro.runtime.ft import StragglerMonitor
 from repro.serving.bucketing import (Bucket, candidate_buckets,
                                      pick_bucket)
@@ -424,6 +425,18 @@ class ServeSession:
         if tel.enabled:
             return tel.tracer.span(name, **args)
         return _NULL_SPAN
+
+    def _begin(self, name: str, **args):
+        """Open a tracer span that :meth:`_end` records, for a region
+        that is not one ``with`` block; None, with no tracer call, when
+        telemetry is off."""
+        tel = self.telemetry
+        return tel.tracer.begin(name, **args) if tel.enabled else None
+
+    def _end(self, opened, **args) -> None:
+        """Record a span :meth:`_begin` opened (nothing for None)."""
+        if opened is not None:
+            self.telemetry.tracer.end(opened, **args)
 
     def _event(self, kind: str, step: Optional[int] = None,
                request_id: Optional[str] = None, **data: Any) -> None:
@@ -906,7 +919,8 @@ class ServeSession:
                                tokens_generated=0, backend=backend)
         deg0 = self.stats.degraded_buckets
         tel = self.telemetry
-        t_act0 = tel.clock() if tel.enabled else 0.0
+        act_span = self._begin("serve.activation", rows=int(rows_n),
+                               prompt_bucket=int(s_pad))
         # Postmortem dump counts at drain entry: any reason dumped
         # during this drain is re-dumped once at the end, so the bundle
         # on disk also reflects what recovery did (e.g. the re-tuned
@@ -1075,86 +1089,95 @@ class ServeSession:
             """Prefill req into row r and scatter its KV/state in;
             False when the prefill raised or produced non-finite logits
             (the request fails, the row stays usable)."""
+            with self._span("serve.admit", request_id=req.request_id):
+                return admit_phases(req, r)
+
+        def admit_phases(req: Request, r: int) -> bool:
+            """The body of :func:`admit`, one span per host phase:
+            prepare, prefill, first token, placement."""
             nonlocal pool
+            rid = req.request_id
             length = len(req.tokens)
             p_len = self._prompt_bucket(req)
-            row_wait[r] = self._clock() - req.submitted_at
-            t_adm0 = tel.clock() if tel.enabled else 0.0
-            if attn_family:
-                nb = blocks_needed(length + req.max_new_tokens - 1, bs)
-                row_blocks[r] = alloc.alloc(nb)
-                tables_np[r, :] = 0
-                tables_np[r, :nb] = row_blocks[r]
-            toks = left_pad_prompts([req.tokens], p_len, self.pad_id)
-            starts = jnp.asarray([p_len - length], jnp.int32)
-            fn = prefill_fn_for(p_len)
-            if dispatch is not None:
-                kind, prob = serve_dispatch_problems(
-                    cfg, 1, p_len, cap)["prefill"]
-                dispatch.propose(kind, prob)
-            t_pf0 = tel.clock() if tel.enabled else 0.0
-            t0 = time.time()
+            with self._span("serve.admit.prepare", request_id=rid):
+                row_wait[r] = self._clock() - req.submitted_at
+                if attn_family:
+                    nb = blocks_needed(length + req.max_new_tokens - 1, bs)
+                    row_blocks[r] = alloc.alloc(nb)
+                    tables_np[r, :] = 0
+                    tables_np[r, :nb] = row_blocks[r]
+                toks = left_pad_prompts([req.tokens], p_len, self.pad_id)
+                starts = jnp.asarray([p_len - length], jnp.int32)
+                fn = prefill_fn_for(p_len)
+                if dispatch is not None:
+                    kind, prob = serve_dispatch_problems(
+                        cfg, 1, p_len, cap)["prefill"]
+                    dispatch.propose(kind, prob)
+            t0 = time.perf_counter()
             try:
-                logits, pcache = fn(params,
-                                    {"tokens": jnp.asarray(toks)},
-                                    starts)
-                jax.block_until_ready(logits)
+                with self._span("serve.prefill", request_id=rid,
+                                prompt_len=int(p_len)):
+                    logits, pcache = fn(params,
+                                        {"tokens": jnp.asarray(toks)},
+                                        starts)
+                    jax.block_until_ready(logits)
             except Exception as e:
                 # Kernel failure during prefill: this request only.
                 fail_admission(req, r, f"prefill raised: {e}")
                 return False
-            dt = time.time() - t0
-            if tel.enabled:
-                tel.tracer.complete("serve.prefill", t_pf0, tel.clock(),
-                                    request_id=req.request_id,
-                                    prompt_len=int(p_len))
+            dt = time.perf_counter() - t0
             if dispatch is not None:
                 dispatch.observe(kind, prob, dt)
             act_stats.prefill_s += dt
             self.stats.prefill_s += dt
-            if self.nan_check and not bool(
-                    np.isfinite(np.asarray(logits[0, -1])).all()):
+            with self._span("serve.admit.first_token", request_id=rid):
+                finite = not self.nan_check or bool(
+                    np.isfinite(np.asarray(logits[0, -1])).all())
+                if finite:
+                    first = int(np.asarray(
+                        jnp.argmax(logits[0, -1], axis=-1)))
+            if not finite:
                 self.stats.poisoned_rows += 1
                 fail_admission(req, r, "non-finite prefill logits")
                 return False
-            first = int(np.asarray(
-                jnp.argmax(logits[0, -1], axis=-1)))
-            if attn_family:
-                # Scatter the row's real prompt KV into its pool
-                # blocks: positions 0..length-1 land in the first
-                # ceil(length/bs) blocks; the tail of the last block is
-                # zero-filled and overwritten by decode writes.
-                nbp = blocks_needed(length, bs)
-                idx = jnp.asarray(row_blocks[r][:nbp], jnp.int32)
+            with self._span("serve.admit.place", request_id=rid):
+                if attn_family:
+                    # Scatter the row's real prompt KV into its pool
+                    # blocks: positions 0..length-1 land in the first
+                    # ceil(length/bs) blocks; the tail of the last block
+                    # is zero-filled and overwritten by decode writes.
+                    nbp = blocks_needed(length, bs)
+                    idx = jnp.asarray(row_blocks[r][:nbp], jnp.int32)
 
-                def place(pool_t, pre):
-                    """Scatter one K/V tensor into the row's blocks."""
-                    real = pre[:, 0, :, p_len - length:, :].astype(
-                        pool_t.dtype)
-                    ln, hkv, _, hd = real.shape
-                    padded = jnp.zeros((ln, hkv, nbp * bs, hd),
-                                       pool_t.dtype)
-                    padded = padded.at[:, :, :length, :].set(real)
-                    blocked = padded.reshape(ln, hkv, nbp, bs, hd)
-                    return pool_t.at[:, idx].set(
-                        blocked.transpose(0, 2, 1, 3, 4))
+                    def place(pool_t, pre):
+                        """Scatter one K/V tensor into the row's blocks."""
+                        real = pre[:, 0, :, p_len - length:, :].astype(
+                            pool_t.dtype)
+                        ln, hkv, _, hd = real.shape
+                        padded = jnp.zeros((ln, hkv, nbp * bs, hd),
+                                           pool_t.dtype)
+                        padded = padded.at[:, :, :length, :].set(real)
+                        blocked = padded.reshape(ln, hkv, nbp, bs, hd)
+                        return pool_t.at[:, idx].set(
+                            blocked.transpose(0, 2, 1, 3, 4))
 
-                pool = {"layers": {
-                    "k": place(pool["layers"]["k"],
-                               pcache["layers"]["k"]),
-                    "v": place(pool["layers"]["v"],
-                               pcache["layers"]["v"])}}
-            else:
-                # Recurrent state is O(1) per row: write row r.
-                pool = jax.tree.map(
-                    lambda e, s: e.at[:, r].set(s[:, 0].astype(e.dtype)),
-                    pool, pcache)
+                    pool = {"layers": {
+                        "k": place(pool["layers"]["k"],
+                                   pcache["layers"]["k"]),
+                        "v": place(pool["layers"]["v"],
+                                   pcache["layers"]["v"])}}
+                else:
+                    # Recurrent state is O(1) per row: write row r.
+                    pool = jax.tree.map(
+                        lambda e, s: e.at[:, r].set(
+                            s[:, 0].astype(e.dtype)),
+                        pool, pcache)
             row_req[r] = req
             row_out[r] = [first]
             row_remaining[r] = req.max_new_tokens - 1
             pos_np[r] = length
             tok_np[r] = first
-            self._running.add(req.request_id)
+            self._running.add(rid)
             self.stats.inflight_admissions += 1
             # TTFT: the engine's batch-1 prefill produced the first
             # token right here — submit -> now on the session clock.
@@ -1167,11 +1190,8 @@ class ServeSession:
                     "serve.inflight_admissions_total").inc()
                 tel.metrics.histogram("serve.ttft_seconds").observe(
                     now - req.submitted_at)
-                tel.lifecycle.admitted(req.request_id,
-                                       req.submitted_at + row_wait[r])
-                tel.lifecycle.token(req.request_id, now)
-                tel.tracer.complete("serve.admit", t_adm0, tel.clock(),
-                                    request_id=req.request_id)
+                tel.lifecycle.admitted(rid, req.submitted_at + row_wait[r])
+                tel.lifecycle.token(rid, now)
             return True
 
         step_fn = None
@@ -1201,9 +1221,12 @@ class ServeSession:
                 else:
                     def make(be, sched):
                         """Jit the recurrent step for one backend."""
-                        return jax.jit(functools.partial(
-                            model.decode_step, backend=be,
-                            schedules=sched))
+                        def step(p, c, t, pos):
+                            """Positional recurrent decode step (named
+                            like the paged one: ``jit_step``)."""
+                            return model.decode_step(
+                                p, c, t, pos, backend=be, schedules=sched)
+                        return jax.jit(step)
                     lower_args = (params, pool,
                                   jnp.asarray(tok_np)[:, None],
                                   jnp.int32(0))
@@ -1214,8 +1237,23 @@ class ServeSession:
                     else None)
             return build
 
+        def note_held(opened, reason: Optional[str]):
+            """Keep, end or open the ``serve.queue.held`` span: open
+            while the queue's head waits with a row free, for
+            ``reason``; ended at the first boundary where it is
+            admitted, no row is free, or the activation ends."""
+            rid = self._queue[0].request_id if reason else None
+            if opened is not None and opened.args == {
+                    "reason": reason, "request_id": rid}:
+                return opened
+            self._end(opened)
+            return (self._begin("serve.queue.held", tid=QUEUE_TID,
+                                reason=reason, request_id=rid)
+                    if reason else None)
+
         step_idx = 0
         inj_blocked = False
+        hold_span = None
         while True:
             with self._span("serve.step", step=self._step_count):
                 inj_blocked = False
@@ -1248,10 +1286,15 @@ class ServeSession:
                             pool = jax.tree.map(lambda p: p[:, gather], pool)
                             self.stats.compactions += 1
                 self._sweep_queue(results)
+                # Why the queue's head stays queued with a row free at
+                # this boundary, if it does (the serve.queue.held span).
+                held = None
                 if self._admission_hold > 0:
                     # A straggler hook asked to shrink admission: skip this
                     # boundary, serve only the rows already in flight.
                     self._admission_hold -= 1
+                    if self._queue and any(q is None for q in row_req):
+                        held = "hold"
                 else:
                     while self._queue:
                         free_rows = [r for r in range(rows_n)
@@ -1279,6 +1322,7 @@ class ServeSession:
                                 # Needs a wider table than this activation
                                 # compiled: defer to the next activation,
                                 # whose geometry is recomputed.
+                                held = "table"
                                 break
                             if (self._faults is not None
                                     and self._faults.alloc_blocked(
@@ -1286,11 +1330,15 @@ class ServeSession:
                                 self._event("alloc_exhausted",
                                             step=self._step_count)
                                 inj_blocked = True
+                                held = "fault"
                                 break   # injected exhaustion: backpressure
                             if not alloc.can_fit(needed):
+                                held = "pool"
                                 break   # backpressure: wait for retirements
+                        hold_span = note_held(hold_span, None)
                         if not admit(self._queue.pop(0), free_rows[0]):
                             continue    # admission fault: row still free
+                hold_span = note_held(hold_span, held)
                 active = [r for r in range(rows_n)
                           if row_req[r] is not None]
                 if not active:
@@ -1309,44 +1357,48 @@ class ServeSession:
                 if dispatch is not None:
                     kind, prob = dec
                     dispatch.propose(kind, prob)
-                t_dec0 = tel.clock() if tel.enabled else 0.0
-                t_step = time.perf_counter()
-                try:
-                    if attn_family:
-                        lg, new_pool = step_fn(params, pool,
-                                               jnp.asarray(tok_np)[:, None],
-                                               jnp.asarray(pos_np),
-                                               jnp.asarray(tables_np))
-                    else:
-                        lg, new_pool = step_fn(params, pool,
-                                               jnp.asarray(tok_np)[:, None],
-                                               jnp.int32(0))
-                except Exception as e:
-                    # A step-level kernel failure is not attributable to one
-                    # row: fail the rows that were in flight (their blocks
-                    # free, partial tokens delivered) but keep the queue and
-                    # the session alive — coarse isolation, not a drain
-                    # abort.
-                    log.warning("decode step raised: %s", e)
-                    self._event("step_exception", step=self._step_count,
-                                error=str(e))
-                    for r in active:
-                        row_fate[r] = (RequestState.FAILED,
-                                       f"decode step raised: {e}")
-                        retire(r)
-                    self._step_count += 1
-                    continue
-                pool = new_pool
-                if self._faults is not None:
-                    for rr in self._faults.nan_rows(self._step_count):
-                        if 0 <= rr < rows_n:
-                            lg = lg.at[rr, -1, :].set(jnp.nan)
-                new_tok = np.asarray(
-                    jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32))
-                finite = (np.asarray(
-                    jnp.all(jnp.isfinite(lg[:, -1]), axis=-1))
-                    if self.nan_check else None)
-                dt = time.perf_counter() - t_step
+                with self._span("serve.decode_step", step=self._step_count,
+                                rows=len(active)):
+                    t_step = time.perf_counter()
+                    with self._span("serve.decode_step.upload"):
+                        inputs = [jnp.asarray(tok_np)[:, None]]
+                        if attn_family:
+                            inputs += [jnp.asarray(pos_np),
+                                       jnp.asarray(tables_np)]
+                        else:
+                            inputs.append(jnp.int32(0))
+                    try:
+                        with self._span("serve.decode_step.launch"):
+                            lg, new_pool = step_fn(params, pool, *inputs)
+                    except Exception as e:
+                        # A step-level kernel failure is not attributable
+                        # to one row: fail the rows that were in flight
+                        # (their blocks free, partial tokens delivered)
+                        # but keep the queue and the session alive —
+                        # coarse isolation, not a drain abort.
+                        log.warning("decode step raised: %s", e)
+                        self._event("step_exception", step=self._step_count,
+                                    error=str(e))
+                        for r in active:
+                            row_fate[r] = (RequestState.FAILED,
+                                           f"decode step raised: {e}")
+                            retire(r)
+                        self._step_count += 1
+                        continue
+                    pool = new_pool
+                    with self._span("serve.decode_step.fetch"):
+                        if self._faults is not None:
+                            for rr in self._faults.nan_rows(self._step_count):
+                                if 0 <= rr < rows_n:
+                                    lg = lg.at[rr, -1, :].set(jnp.nan)
+                        new_tok = np.asarray(
+                            jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32))
+                    finite = None
+                    if self.nan_check:
+                        with self._span("serve.decode_step.check"):
+                            finite = np.asarray(
+                                jnp.all(jnp.isfinite(lg[:, -1]), axis=-1))
+                    dt = time.perf_counter() - t_step
                 # Injected slowdowns count once: the magnitude is read
                 # here and reused by the straggler record and the
                 # watchdog taps below (slow_extra_s logs its firing).
@@ -1356,9 +1408,6 @@ class ServeSession:
                 self.stats.decode_s += dt
                 bucket_entry()["decode_s"] += dt
                 if tel.enabled:
-                    tel.tracer.complete("serve.decode_step", t_dec0,
-                                        tel.clock(), step=self._step_count,
-                                        rows=len(active))
                     tel.metrics.histogram(
                         "serve.decode_step_seconds").observe(dt)
                 if dispatch is not None:
@@ -1457,6 +1506,7 @@ class ServeSession:
                              "free_blocks": (alloc.num_free
                                              if attn_family else None)})
 
+        note_held(hold_span, None)
         act_stats.recompiles = recompiles
         act_stats.recompile_s = recompile_s
         act_stats.degraded = self.stats.degraded_buckets > deg0
@@ -1475,10 +1525,7 @@ class ServeSession:
                 prefix="serve.exec_cache.",
                 help="executable-cache snapshot")
             self._straggler.export_metrics(tel.metrics)
-            tel.tracer.complete("serve.activation", t_act0, tel.clock(),
-                                rows=int(rows_n),
-                                prompt_bucket=int(s_pad),
-                                steps=int(step_idx))
+        self._end(act_span, steps=int(step_idx))
         if self.registry is not None and step_idx:
             key = reg.RegistryKey.make(
                 "serve_decode",
@@ -1564,7 +1611,6 @@ class ServeSession:
         pallas = backend == "pallas"
         model_backend = "pallas" if pallas else "xla"
         deg0 = self.stats.degraded_buckets
-        tel = self.telemetry
 
         problems = (serve_dispatch_problems(cfg, bsz, prompt_len, total)
                     if dispatch is not None else {})
@@ -1622,7 +1668,8 @@ class ServeSession:
                 else None)
 
         prefill_fn, _ = self._compile(prefill_key, build_prefill)
-        t_pf0 = tel.clock() if tel.enabled else 0.0
+        pf_span = self._begin("serve.prefill", batch=int(bsz),
+                              prompt_len=int(prompt_len))
         t0 = time.time()
         logits, cache = (prefill_fn(params, batch) if starts is None
                          else prefill_fn(params, batch, starts))
@@ -1644,10 +1691,7 @@ class ServeSession:
         cache = jax.tree.map(fit, full, cache)
         jax.block_until_ready(cache)
         prefill_s = time.time() - t0
-        if tel.enabled:
-            tel.tracer.complete("serve.prefill", t_pf0, tel.clock(),
-                                batch=int(bsz),
-                                prompt_len=int(prompt_len))
+        self._end(pf_span)
 
         def pick(lg, key):
             """Next token per row: greedy argmax or sampled."""
@@ -1720,7 +1764,8 @@ class ServeSession:
         switch_blocked = False  # budget spent on an uncached commit
         dec = problems.get("decode")
 
-        t_dec0 = tel.clock() if tel.enabled else 0.0
+        dec_span = self._begin("serve.decode", batch=int(bsz),
+                               steps=int(max_new_tokens - 1))
         t1 = time.time()
         for i in range(max_new_tokens - 1):
             t_step = time.perf_counter()
@@ -1799,10 +1844,7 @@ class ServeSession:
                             self.stats.commits_seen += 1
         jax.block_until_ready(tok)
         decode_s = time.time() - t1 - recompile_s
-        if tel.enabled:
-            tel.tracer.complete("serve.decode", t_dec0, tel.clock(),
-                                batch=int(bsz),
-                                steps=int(max_new_tokens - 1))
+        self._end(dec_span)
         report = None
         if prefill_bundle is not None:
             # Resolved once per (prefill, decode) bundle pair and

@@ -182,7 +182,7 @@ def test_span_tracer_deterministic_exports():
         with tr.span("outer", step=0):
             with tr.span("inner"):
                 tr.instant("tick", n=1)
-        tr.complete("manual", 100.002, 100.004, what="x")
+        tr.end(tr.begin("manual", what="x"))
         tr.async_begin("request", "r1", request_id="r1")
         tr.async_end("request", "r1", state="COMPLETED")
         return tr
@@ -327,7 +327,7 @@ def test_telemetry_off_never_touches_tracer(monkeypatch):
     def boom(*a, **k):
         raise AssertionError("telemetry-off path touched the tracer")
 
-    for name in ("span", "complete", "instant", "async_begin",
+    for name in ("span", "begin", "end", "instant", "async_begin",
                  "async_end"):
         monkeypatch.setattr(NullTracer, name, boom)
     assert NULL_TELEMETRY.enabled is False

@@ -118,3 +118,55 @@ def test_ssm_scan(one_chip, seq, block_d):
         ((D_INNER, STATE), F32), ((D_INNER,), BF16),
         ((1, D_INNER, STATE), F32))
     assert "tpu_custom_call" in text
+
+
+def _benchmark_kernel_names():
+    """The benchmark's trace reduction (``benchmarks/chip/harness/
+    kernels.py``), which matches the kernels by these names."""
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+            / "chip" / "harness" / "kernels.py")
+    spec = importlib.util.spec_from_file_location("bench_kernels", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "ssm_scan"])
+def test_kernel_name_survives_lowering(one_chip, kernel):
+    """The custom call keeps the kernel's pinned name inside a jitted
+    caller of any other name, so the device trace finds it whatever
+    wraps it."""
+    import re
+
+    from repro.kernels.decode_attention.kernel import (
+        PAGED_DECODE_KERNEL_NAME)
+    from repro.kernels.ssm_scan.kernel import SSM_SCAN_KERNEL_NAME
+    bench = _benchmark_kernel_names()
+    if kernel == "paged_decode":
+        pool = ((512, HEADS, 16, HEAD_DIM), BF16)
+
+        @jax.jit
+        def some_caller(q, k, v, t, p):
+            return paged_decode_attention_pallas(q, k, v, t, p,
+                                                 interpret=False)
+        shapes = (((8, HEADS, 1, HEAD_DIM), BF16), pool, pool,
+                  ((8, 64), I32), ((8,), I32))
+        name, matches = PAGED_DECODE_KERNEL_NAME, bench.is_paged_decode_kernel
+    else:
+        @jax.jit
+        def some_caller(x, dt, b, c, a, d, h0):
+            return ssm_scan_pallas(x, dt, b, c, a, d, h0=h0, interpret=False)
+        shapes = (((1, 1, D_INNER), BF16), ((1, 1, D_INNER), F32),
+                  ((1, 1, STATE), F32), ((1, 1, STATE), F32),
+                  ((D_INNER, STATE), F32), ((D_INNER,), BF16),
+                  ((1, D_INNER, STATE), F32))
+        name, matches = SSM_SCAN_KERNEL_NAME, bench.is_ssm_scan_kernel
+    text = _compile_text(lambda *a: some_caller(*a), one_chip, *shapes)
+    calls = [line.strip() for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert calls
+    for call in calls:
+        assert re.match(rf"%{name}(\.\d+)* = ", call), call[:120]
+        assert matches(call)
