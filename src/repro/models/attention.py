@@ -281,10 +281,11 @@ def paged_decode_attention(q: jnp.ndarray, pool_k: jnp.ndarray,
     anywhere (conventionally the reserved block 0): their logical
     positions exceed ``pos`` so the validity mask discards them.
 
-    ``backend="pallas"`` streams one pool block per grid step through
-    the block-table-aware gather kernel, skipping blocks wholly beyond
-    each row's ``pos`` via scalar prefetch; the XLA path materialises
-    the gather (reference semantics).  ``schedule`` is accepted for
+    ``backend="pallas"`` runs the block-table-aware gather kernel on a
+    ``(B, MB)`` grid: each step streams one pool block of one row with
+    every KV head in it, and blocks wholly beyond the row's ``pos`` are
+    neither fetched nor computed (scalar prefetch); the XLA path
+    materialises the gather (reference semantics).  ``schedule`` is accepted for
     signature parity but paging fixes the streaming granularity at the
     block size."""
     if backend == "pallas":

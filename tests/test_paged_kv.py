@@ -126,17 +126,33 @@ def test_compaction_repacks_tables_and_returns_gather_map():
 # --------------------------------------- paged primitives vs monolithic
 
 
+# (hq, hkv, d, lens, mb, junk_tail): bs = 4, one row per entry of lens.
+# With junk_tail, table slots past a row's last live block point at the
+# reserved block, filled with large finite values: a read of them that
+# the validity mask lets through moves the output far off.
+PAGED_CASES = {
+    "gqa2": (4, 2, 8, (5, 9), 3, False),
+    "group1": (4, 4, 8, (5, 9), 3, False),
+    "group4": (8, 2, 8, (5, 9), 3, False),
+    "d96": (4, 2, 96, (5, 9), 3, False),
+    "pos0": (4, 2, 8, (0, 9), 3, True),
+    "wide_table": (4, 2, 8, (5, 9), 6, True),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_CASES))
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
-def test_paged_decode_matches_monolithic_cache(backend):
+def test_paged_decode_matches_monolithic_cache(backend, case):
     """One decode step through block tables == the same step through a
     contiguous cache, for rows at different depths."""
     from repro.models import attention as attn
 
+    hq, hkv, d, lens, mb, junk_tail = PAGED_CASES[case]
     rng = np.random.RandomState(0)
-    b, hq, hkv, d, bs, mb = 2, 4, 2, 8, 4, 3
+    b, bs = len(lens), 4
     n_blocks = 1 + b * mb
     s = mb * bs
-    lens = np.array([5, 9], np.int32)  # per-row logical depth
+    lens = np.array(lens, np.int32)  # per-row logical depth
     k = rng.randn(b, hkv, s, d).astype(np.float32)
     v = rng.randn(b, hkv, s, d).astype(np.float32)
     q = rng.randn(b, hq, 1, d).astype(np.float32)
@@ -145,12 +161,14 @@ def test_paged_decode_matches_monolithic_cache(backend):
                                 jnp.asarray(v), jnp.asarray(lens),
                                 backend=backend)
     # paged: scatter the same K/V into out-of-order pool blocks
-    tables = np.zeros((b, mb), np.int32)
-    order = [5, 1, 3, 2, 6, 4]  # deliberately non-contiguous
-    pool_k = np.zeros((n_blocks, hkv, bs, d), np.float32)
-    pool_v = np.zeros((n_blocks, hkv, bs, d), np.float32)
+    tables = np.full((b, mb), RESERVED_BLOCK, np.int32)
+    order = 1 + np.random.RandomState(1).permutation(b * mb)
+    pool_k = np.full((n_blocks, hkv, bs, d), 1e4 if junk_tail else 0.0,
+                     np.float32)
+    pool_v = pool_k.copy()
     for row in range(b):
-        for j in range(mb):
+        live = lens[row] // bs + 1 if junk_tail else mb
+        for j in range(live):
             blk = order[row * mb + j]
             tables[row, j] = blk
             pool_k[blk] = k[row, :, j * bs:(j + 1) * bs]

@@ -13,13 +13,16 @@ The topology is described inside a fixture and never at import time:
 only one process may load the TPU library, and the suite runs under
 several pytest-xdist workers.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_pallas, paged_decode_attention_pallas)
+    PAGED_DECODE_KERNEL_NAME, decode_attention_pallas,
+    paged_decode_attention_pallas)
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.ssm_scan.kernel import ssm_scan_pallas
 
@@ -91,14 +94,24 @@ def test_decode_attention(one_chip, seq, block_kv):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_attention(one_chip):
-    pool = ((512, HEADS, 16, HEAD_DIM), BF16)
+@pytest.mark.parametrize("pool_blocks,table_width,kv_heads,head_dim", [
+    (512, 64, HEADS, HEAD_DIM),
+    (257, 48, HEADS, HEAD_DIM),   # the phi3 benchmark cells' pool and table
+    (257, 48, 8, 128),            # GQA: four query heads per KV head
+])
+def test_paged_decode_attention(one_chip, pool_blocks, table_width,
+                                kv_heads, head_dim):
+    """Eight rows of 16-token blocks; the custom call keeps the name the
+    benchmark's roofline reader matches on."""
+    pool = ((pool_blocks, kv_heads, 16, head_dim), BF16)
     text = _compile_text(
         lambda q, k, v, t, p: paged_decode_attention_pallas(
             q, k, v, t, p, interpret=False),
-        one_chip, ((8, HEADS, 1, HEAD_DIM), BF16), pool, pool,
-        ((8, 64), I32), ((8,), I32))
+        one_chip, ((8, HEADS, 1, head_dim), BF16), pool, pool,
+        ((8, table_width), I32), ((8,), I32))
     assert "tpu_custom_call" in text
+    assert _kernel_calls(text, PAGED_DECODE_KERNEL_NAME,
+                         _benchmark_kernel_names().is_paged_decode_kernel)
 
 
 @pytest.mark.parametrize("seq,block_d", [
@@ -138,10 +151,6 @@ def test_kernel_name_survives_lowering(one_chip, kernel):
     """The custom call keeps the kernel's pinned name inside a jitted
     caller of any other name, so the device trace finds it whatever
     wraps it."""
-    import re
-
-    from repro.kernels.decode_attention.kernel import (
-        PAGED_DECODE_KERNEL_NAME)
     from repro.kernels.ssm_scan.kernel import SSM_SCAN_KERNEL_NAME
     bench = _benchmark_kernel_names()
     if kernel == "paged_decode":
@@ -164,9 +173,15 @@ def test_kernel_name_survives_lowering(one_chip, kernel):
                   ((1, D_INNER, STATE), F32))
         name, matches = SSM_SCAN_KERNEL_NAME, bench.is_ssm_scan_kernel
     text = _compile_text(lambda *a: some_caller(*a), one_chip, *shapes)
+    assert _kernel_calls(text, name, matches)
+
+
+def _kernel_calls(text, name, matches):
+    """The compiled text's kernel custom calls; each must carry ``name``
+    and be found by the benchmark's matcher ``matches``."""
     calls = [line.strip() for line in text.splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line]
-    assert calls
     for call in calls:
         assert re.match(rf"%{name}(\.\d+)* = ", call), call[:120]
         assert matches(call)
+    return calls
